@@ -1,8 +1,1 @@
-"""repro: jax_pallas reproduction of LoCo (low-bit communication adaptor).
-
-Importing any ``repro.*`` module installs the JAX version-compat shims
-(see :mod:`repro.compat`) so the codebase can target one API surface.
-"""
-from repro import compat as _compat
-
-_compat.install()
+"""repro: jax_pallas reproduction of LoCo (low-bit communication adaptor)."""

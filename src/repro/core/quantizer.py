@@ -135,60 +135,75 @@ def quant_tensor(
 
 
 # ---------------------------------------------------------------------------
-# int4 <-> int8 packing (two nibbles per byte; wire format)
+# sub-byte packing (wire format): half-split int4, eighth-split signs
 # ---------------------------------------------------------------------------
+
+PACK_GRANULE = 256  # elements per packing granule (= one kernel row, QBLOCK)
+SIGN_PACK = 8       # signs per wire byte
+
+
+def _granules(x: jax.Array, parts: int) -> tuple[jax.Array, int]:
+    """View the last axis as granules of ``PACK_GRANULE`` elements (or one
+    granule when it is shorter) split into ``parts`` contiguous slices."""
+    n = x.shape[-1]
+    g = min(n, PACK_GRANULE)
+    assert n % g == 0 and g % parts == 0, (x.shape, parts)
+    return x.reshape(*x.shape[:-1], n // g, g), g // parts
+
 
 def pack_int4(q: jax.Array) -> jax.Array:
     """Pack int8-held int4 values (in [-8, 7]) into half-length int8.
 
-    Layout: byte = (hi << 4) | (lo & 0xF), element 2i -> lo, 2i+1 -> hi.
+    Layout, per granule of 256 elements: byte ``k`` holds element ``k`` in
+    its low nibble and element ``k + 128`` in its high nibble.  Both halves
+    are contiguous 128-lane slices, so the TPU tiles every operand densely
+    (an interleaved even/odd layout leaves a minor dimension of 2, padded
+    64x).  The shifts run in int32: ``(hi << 4) | (lo & 0xF)`` of signed
+    nibbles is already in the int8 range.
     """
-    assert q.shape[-1] % 2 == 0
-    lo = q[..., 0::2].astype(jnp.uint8) & 0xF
-    hi = q[..., 1::2].astype(jnp.uint8) & 0xF
-    return ((hi << 4) | lo).astype(jnp.int8)
+    qg, w = _granules(q.astype(jnp.int32), 2)
+    lo, hi = qg[..., :w], qg[..., w:]
+    return ((hi << 4) | (lo & 0xF)).astype(jnp.int8).reshape(
+        *q.shape[:-1], q.shape[-1] // 2)
 
 
 def unpack_int4(p: jax.Array) -> jax.Array:
     """Inverse of :func:`pack_int4`; returns int8 values in [-8, 7]."""
-    b = p.astype(jnp.uint8)
-    lo = (b & 0xF).astype(jnp.int8)
-    hi = ((b >> 4) & 0xF).astype(jnp.int8)
-    # sign-extend nibbles: v >= 8 -> v - 16
-    lo = jnp.where(lo >= 8, lo - 16, lo)
-    hi = jnp.where(hi >= 8, hi - 16, hi)
-    out = jnp.stack([lo, hi], axis=-1)
-    return out.reshape(*p.shape[:-1], p.shape[-1] * 2)
-
-
-# ---------------------------------------------------------------------------
-# sign packing (onebit wire format: 8 signs per byte)
-# ---------------------------------------------------------------------------
-
-SIGN_PACK = 8  # signs per wire byte
+    b = p.astype(jnp.int32)
+    lo = ((b & 0xF) ^ 8) - 8          # sign-extend the low nibble
+    hi = b >> 4                        # arithmetic shift sign-extends
+    n = p.shape[-1] * 2
+    g = min(n, PACK_GRANULE)
+    lo = lo.reshape(*p.shape[:-1], n // g, g // 2)
+    hi = hi.reshape(*p.shape[:-1], n // g, g // 2)
+    out = jnp.concatenate([lo, hi], axis=-1).astype(jnp.int8)
+    return out.reshape(*p.shape[:-1], n)
 
 
 def pack_signs(bits: jax.Array) -> jax.Array:
     """Pack 0/1 sign bits into uint8 bytes, 8 per byte.
 
-    Layout: bit j of byte k = element 8k + j (LSB first), mirroring
-    :func:`pack_int4`'s strided-lane layout so the Pallas sign-pack kernel
-    can produce identical bytes without an in-register transpose.
+    Layout, per granule of 256 elements: bit ``j`` of byte ``k`` is
+    element ``32 j + k`` -- the eight contiguous 32-lane slices of the
+    granule, the same split-not-interleave rule as :func:`pack_int4`, so
+    the Pallas sign-pack kernel produces identical bytes from whole slices.
     """
-    assert bits.shape[-1] % SIGN_PACK == 0, bits.shape
-    b = bits.astype(jnp.uint8)
-    out = b[..., 0::SIGN_PACK]
+    bg, w = _granules(bits.astype(jnp.int32), SIGN_PACK)
+    out = bg[..., :w]
     for j in range(1, SIGN_PACK):
-        out = out | (b[..., j::SIGN_PACK] << j)
-    return out
+        out = out | (bg[..., j * w:(j + 1) * w] << j)
+    return out.astype(jnp.uint8).reshape(
+        *bits.shape[:-1], bits.shape[-1] // SIGN_PACK)
 
 
 def unpack_signs(p: jax.Array) -> jax.Array:
     """Inverse of :func:`pack_signs`; returns uint8 values in {0, 1}."""
-    b = p.astype(jnp.uint8)
-    parts = [(b >> j) & 1 for j in range(SIGN_PACK)]
-    out = jnp.stack(parts, axis=-1)
-    return out.reshape(*p.shape[:-1], p.shape[-1] * SIGN_PACK)
+    b = p.astype(jnp.int32)
+    n = p.shape[-1] * SIGN_PACK
+    g = min(n, PACK_GRANULE)
+    b = b.reshape(*p.shape[:-1], n // g, g // SIGN_PACK)
+    out = jnp.concatenate([(b >> j) & 1 for j in range(SIGN_PACK)], axis=-1)
+    return out.astype(jnp.uint8).reshape(*p.shape[:-1], n)
 
 
 # ---------------------------------------------------------------------------

@@ -10,10 +10,10 @@ ACT_BLOCK is 512 (= the wire granule of core/act_comm, 4 VREG lanes of
 128), so a pallas row block of 32 rows is 16K elements in VMEM -- the same
 budget loco_quant uses at (64, 256).
 
-Like every kernel in this package the cell runs under ``interpret=True``
-off-TPU; core/act_comm keeps a jnp reference as the default path (interpret
-mode is far too slow for the CPU test/bench loops) and routes here only
-when ``REPRO_ACT_KERNELS=1`` -- parity is pinned by tests/test_act_comm.py.
+Like every kernel in this package the cell takes ``interpret`` explicitly
+(``kernels/ops.py`` chooses it by platform); core/act_comm keeps a jnp
+reference as the default path and routes here only when
+``REPRO_ACT_KERNELS=1`` -- parity is pinned by tests/test_act_comm.py.
 """
 from __future__ import annotations
 
@@ -23,15 +23,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.loco_quant import _auto_rows
+
 ACT_BLOCK = 512
 QMAX = 127.0
-
-
-def _auto_rows(rows_total: int) -> int:
-    for r in (32, 16, 8, 4, 2, 1):
-        if rows_total % r == 0:
-            return r
-    return 1
+ROWS = 32         # rows of ACT_BLOCK per pallas block (multiple of 32: int8)
 
 
 def _encode_kernel(h_ref, q_ref, s_ref):
@@ -47,15 +43,15 @@ def _decode_kernel(q_ref, s_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "rows"))
-def act_encode(h: jax.Array, *, interpret: bool = True,
+def act_encode(h: jax.Array, *, interpret: bool,
                rows: int | None = None) -> tuple[jax.Array, jax.Array]:
     """``(rows, ACT_BLOCK)`` f32 -> (int8 codes, f32 scales ``(rows,)``)."""
     rows_total, blk = h.shape
     assert blk == ACT_BLOCK, h.shape
-    R = rows or _auto_rows(rows_total)
+    R = rows or _auto_rows(rows_total, ROWS)
     q, s = pl.pallas_call(
         _encode_kernel,
-        grid=(rows_total // R,),
+        grid=(pl.cdiv(rows_total, R),),
         in_specs=[pl.BlockSpec((R, ACT_BLOCK), lambda i: (i, 0))],
         out_specs=(
             pl.BlockSpec((R, ACT_BLOCK), lambda i: (i, 0)),
@@ -71,15 +67,15 @@ def act_encode(h: jax.Array, *, interpret: bool = True,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "rows"))
-def act_decode(q: jax.Array, scale: jax.Array, *, interpret: bool = True,
+def act_decode(q: jax.Array, scale: jax.Array, *, interpret: bool,
                rows: int | None = None) -> jax.Array:
     """(int8 codes, scales) -> ``(rows, ACT_BLOCK)`` f32."""
     rows_total, blk = q.shape
     assert blk == ACT_BLOCK, q.shape
-    R = rows or _auto_rows(rows_total)
+    R = rows or _auto_rows(rows_total, ROWS)
     return pl.pallas_call(
         _decode_kernel,
-        grid=(rows_total // R,),
+        grid=(pl.cdiv(rows_total, R),),
         in_specs=[
             pl.BlockSpec((R, ACT_BLOCK), lambda i: (i, 0)),
             pl.BlockSpec((R, 1), lambda i: (i, 0)),

@@ -7,7 +7,10 @@ into VMEM-resident (ROWS, 256) blocks (256 = quantizer block = 2 VREG
 lanes of 128) and fuse:
 
 * ``fused_compress``: error-decode + compensate + per-block absmax
-  quantize (4- or 8-bit) + nibble-pack + error update + error encode
+  quantize (4- or 8-bit) + nibble-pack (the half-split layout of
+  ``quantizer.pack_int4``: one kernel row is one packing granule, its two
+  128-lane halves fill the low and high nibbles) + error update + error
+  encode
   -- one pass over the gradient, one pass out for payload/scales/error.
   Parameterized by ``bits`` (4: nibble-packed int4, 8: int8) and ``err``
   (``"f8"``: LoCo's scaled f8_e4m3 storage with ±448 saturation;
@@ -21,9 +24,10 @@ lanes of 128) and fuse:
 Weak spots the MXU can't help with (this is pure VPU work); the win is
 fusion: the unfused jnp path reads/writes the f32 gradient ~6x.
 
-All kernels run under ``interpret=True`` on CPU (how this repo validates
-them -- see tests/test_kernels.py) and compile for TPU via the same
-``pl.pallas_call`` with explicit ``BlockSpec`` tiling.
+Every entry point takes ``interpret`` explicitly: ``kernels/ops.py``
+chooses it from the platform (compiled on TPU, interpreted elsewhere).
+tests/test_kernels.py checks each kernel against its oracle in interpret
+mode; tests/test_tpu_compile.py compiles each for a described v5e.
 """
 from __future__ import annotations
 
@@ -63,20 +67,21 @@ def _compress_kernel(g_ref, e_ref, q_ref, s_ref, enew_ref, *,
         enew = e_tilde
     enew_ref[...] = enew.astype(enew_ref.dtype)
     s_ref[...] = scale[:, :1]
-    qi = q.astype(jnp.int8)
-    if bits == 4:
-        lo = qi[:, 0::2].astype(jnp.uint8) & 0xF
-        hi = qi[:, 1::2].astype(jnp.uint8) & 0xF
-        q_ref[...] = ((hi << 4) | lo).astype(jnp.int8)
+    qi = q.astype(jnp.int32)
+    if bits == 4:  # half-split nibbles (quantizer.pack_int4), int32 shifts
+        half = QBLOCK // 2
+        lo, hi = qi[:, :half], qi[:, half:]
+        q_ref[...] = ((hi << 4) | (lo & 0xF)).astype(jnp.int8)
     else:
-        q_ref[...] = qi
+        q_ref[...] = qi.astype(jnp.int8)
 
 
-def _auto_rows(rows_total: int) -> int:
-    for r in (64, 32, 16, 8, 4, 2, 1):
-        if rows_total % r == 0:
-            return r
-    return 1
+def _auto_rows(rows_total: int, max_rows: int = ROWS) -> int:
+    """Row block: ``max_rows`` (a multiple of 32, the sublane tile of the
+    8-bit operands every kernel here has), or the whole array when it is
+    shorter.  The grid is ``cdiv(rows_total, R)``: a ragged last block is
+    padded on read and masked on write, and every kernel is row-local."""
+    return min(rows_total, max_rows)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "beta", "escale", "err",
@@ -89,7 +94,7 @@ def fused_compress(
     beta: float,
     escale: float,
     err: str = "f8",
-    interpret: bool = True,
+    interpret: bool,
     rows: int | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Flat (n,) gradient + (n,) error -> (payload, scales (n//QBLOCK,), e_new (n,)).
@@ -97,8 +102,7 @@ def fused_compress(
     payload is (n//2,) nibble-packed int8 at 4 bits, (n,) int8 at 8 bits;
     e_new keeps the input error dtype (f8_e4m3 for ``err="f8"``, bf16 for
     ``err="bf16"``).  n must be a multiple of 2*QBLOCK (the FSDP padding
-    guarantees multiples of 512); the row-block size adapts so the grid
-    tiles exactly.
+    guarantees multiples of 512).
     """
     n = g.shape[0]
     assert bits in (4, 8), bits
@@ -106,7 +110,7 @@ def fused_compress(
     assert n % (2 * QBLOCK) == 0, n
     rows_total = n // QBLOCK
     R = rows or _auto_rows(rows_total)
-    grid = (rows_total // R,)
+    grid = (pl.cdiv(rows_total, R),)
     pay_cols = QBLOCK // 2 if bits == 4 else QBLOCK
     gm = g.reshape(rows_total, QBLOCK)
     em = e.reshape(rows_total, QBLOCK)
@@ -135,13 +139,13 @@ def fused_compress(
 
 
 def loco_compress(g, e8, *, beta: float, escale: float, bits: int = 4,
-                  interpret: bool = True, rows: int | None = None):
+                  interpret: bool, rows: int | None = None):
     """LoCo specialization: f8 error storage, moving-average update."""
     return fused_compress(g, e8, bits=bits, beta=beta, escale=escale,
                           err="f8", interpret=interpret, rows=rows)
 
 
-def ef_compress(g, e, *, bits: int = 4, interpret: bool = True,
+def ef_compress(g, e, *, bits: int = 4, interpret: bool,
                 rows: int | None = None):
     """EF specialization: beta=1 (full last-step error), bf16 storage."""
     return fused_compress(g, e, bits=bits, beta=1.0, escale=1.0,
@@ -155,17 +159,15 @@ def ef_compress(g, e, *, bits: int = 4, interpret: bool = True,
 def _dequant_mean_kernel(q_ref, s_ref, out_ref, *, bits: int):
     q = q_ref[...]                                      # (D, ROWS, pay_cols) int8
     s = s_ref[...]                                      # (D, ROWS, 1) f32
-    if bits == 4:
-        b = q.astype(jnp.uint8)
-        lo = (b & 0xF).astype(jnp.int8)
-        hi = ((b >> 4) & 0xF).astype(jnp.int8)
-        lo = jnp.where(lo >= 8, lo - 16, lo).astype(jnp.float32)
-        hi = jnp.where(hi >= 8, hi - 16, hi).astype(jnp.float32)
-        vals = jnp.stack([lo, hi], axis=-1).reshape(q.shape[0], q.shape[1], QBLOCK)
+    if bits == 4:  # half-split nibbles (quantizer.unpack_int4), int32 shifts
+        b = q.astype(jnp.int32)
+        lo = (((b & 0xF) ^ 8) - 8).astype(jnp.float32)
+        hi = (b >> 4).astype(jnp.float32)
+        half = QBLOCK // 2
+        out_ref[:, :half] = jnp.mean(lo / s, axis=0)
+        out_ref[:, half:] = jnp.mean(hi / s, axis=0)
     else:
-        vals = q.astype(jnp.float32)
-    vals = vals / s
-    out_ref[...] = jnp.mean(vals, axis=0)
+        out_ref[...] = jnp.mean(q.astype(jnp.float32) / s, axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret", "rows"))
@@ -174,7 +176,7 @@ def dequant_mean(
     scales: jax.Array,   # (D, n/D/QBLOCK) f32
     *,
     bits: int = 4,
-    interpret: bool = True,
+    interpret: bool,
     rows: int | None = None,
 ) -> jax.Array:
     """Received all-to-all rows -> fp32 mean gradient chunk (n/D,)."""
@@ -184,7 +186,7 @@ def dequant_mean(
     assert n_chunk % (2 * QBLOCK) == 0, n_chunk
     rows_total = n_chunk // QBLOCK
     R = rows or _auto_rows(rows_total)
-    grid = (rows_total // R,)
+    grid = (pl.cdiv(rows_total, R),)
     pay_cols = QBLOCK // 2 if bits == 4 else QBLOCK
     pm = payload.reshape(D, rows_total, pay_cols)
     sm = scales.reshape(D, rows_total, 1)

@@ -1,9 +1,8 @@
 """jit'd public wrappers for the Pallas kernels + fast-path registration.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernel body then executes exactly as written, which is how correctness is
-validated) and False on TPU, where the same BlockSpec tiling compiles to
-Mosaic.  Callers can force either via the ``REPRO_PALLAS_INTERPRET`` env var.
+Interpret mode is chosen here, by platform, and nowhere else: on TPU the
+BlockSpec tiling compiles to Mosaic; on any other backend the kernel body
+runs in the Pallas interpreter, which is how the CPU tests check it.
 
 Importing this module registers every fused fast path with the codec
 registry (``repro.core.codec.register_fastpath``); the codec layer imports
@@ -30,8 +29,6 @@ otherwise; parity pinned by tests/test_act_comm.py).
 """
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
@@ -40,9 +37,6 @@ from repro.kernels import loco_quant, sign_pack
 
 
 def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
 
 

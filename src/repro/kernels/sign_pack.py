@@ -3,8 +3,9 @@
 The onebit strategy (1-bit Adam lineage) ships one sign per element with a
 per-segment L1 scale.  The unfused jnp path materializes the 0/1 mask, the
 ±scale reconstruction and the error update as separate f32-wide passes;
-this kernel does sign-extract, LSB-first bit pack (bit j of byte k =
-element 8k+j, matching ``repro.core.quantizer.pack_signs``) and the
+this kernel does sign-extract, LSB-first bit pack (per 256-element kernel
+row, bit j of byte k = element 32j+k: eight contiguous 32-lane slices,
+matching ``repro.core.quantizer.pack_signs``) and the
 error-feedback update ``e_new = h - (2b-1)*scale`` in one pass, writing
 1/8th byte per element of payload plus the bf16 error.
 
@@ -12,8 +13,9 @@ The L1 scale is a *global* mean over the segment, so it is computed outside
 (one cheap reduction over ``h``) and enters the kernel as a (1, 1) scalar
 operand mapped to every grid step.
 
-Runs under ``interpret=True`` on CPU (the validation harness) and compiles
-for TPU via the same BlockSpec tiling (see tests/test_kernels.py).
+``interpret`` is explicit, chosen by platform in ``kernels/ops.py``;
+tests/test_kernels.py checks the kernel against its oracle in interpret
+mode and tests/test_tpu_compile.py compiles it for a described v5e.
 """
 from __future__ import annotations
 
@@ -31,13 +33,15 @@ SIGN_PACK = 8  # signs per wire byte (= quantizer.SIGN_PACK)
 def _sign_pack_kernel(h_ref, scale_ref, q_ref, enew_ref):
     h = h_ref[...].astype(jnp.float32)                  # (ROWS, QBLOCK)
     scale = scale_ref[0, 0]
-    bits = (h > 0).astype(jnp.uint8)
-    d = (2.0 * bits.astype(jnp.float32) - 1.0) * scale
+    pos = h > 0
+    d = jnp.where(pos, scale, -scale)
     enew_ref[...] = (h - d).astype(enew_ref.dtype)
-    packed = bits[:, 0::SIGN_PACK]
+    bits = pos.astype(jnp.int32)
+    w = QBLOCK // SIGN_PACK
+    packed = bits[:, :w]
     for j in range(1, SIGN_PACK):
-        packed = packed | (bits[:, j::SIGN_PACK] << j)
-    q_ref[...] = packed
+        packed = packed | (bits[:, j * w:(j + 1) * w] << j)
+    q_ref[...] = packed.astype(jnp.uint8)
 
 
 @functools.partial(jax.jit, static_argnames=("state_dtype", "interpret", "rows"))
@@ -46,7 +50,7 @@ def onebit_pack(
     scale: jax.Array,
     *,
     state_dtype=jnp.bfloat16,
-    interpret: bool = True,
+    interpret: bool,
     rows: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Compensated flat (n,) gradient + scalar L1 scale ->
@@ -58,7 +62,7 @@ def onebit_pack(
     assert n % (2 * QBLOCK) == 0, n
     rows_total = n // QBLOCK
     R = rows or _auto_rows(rows_total)
-    grid = (rows_total // R,)
+    grid = (pl.cdiv(rows_total, R),)
     hm = h.astype(jnp.float32).reshape(rows_total, QBLOCK)
     sm = jnp.asarray(scale, jnp.float32).reshape(1, 1)
     out_shapes = (

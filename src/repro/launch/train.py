@@ -13,7 +13,11 @@ surface a cluster launch would use.  Examples:
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import os
+import tempfile
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -91,13 +95,6 @@ def build_args(argv=None):
                          "into exchange(k)'s async window over double-"
                          "buffered pack buffers (bit-exact; --no-overlap "
                          "keeps the single-sync-region schedule)")
-    ap.add_argument("--xla-lhs", default=None, choices=["tpu", "gpu"],
-                    help="enable XLA's latency-hiding scheduler for the "
-                         "named backend (appends the backend-specific flag "
-                         "to XLA_FLAGS before first jax use). Strictly "
-                         "opt-in: the flag set is backend-specific and an "
-                         "unknown flag aborts XLA startup, so CPU runs "
-                         "must not set this")
     ap.add_argument("--telemetry", action="store_true",
                     help="compute the in-graph compression-health metrics "
                          "(error norms, saturation/clip rates, scale stats, "
@@ -123,7 +120,8 @@ def build_args(argv=None):
                     help="capture a jax.profiler trace for the inclusive "
                          "step window N:M (phase annotation via "
                          "loco/encode|exchange|decode|apply scopes)")
-    ap.add_argument("--profile-dir", default="/tmp/loco_trace",
+    ap.add_argument("--profile-dir",
+                    default=os.path.join(tempfile.gettempdir(), "loco_trace"),
                     help="output dir for --profile-steps traces")
     ap.add_argument("--optimizer", default="adam")
     ap.add_argument("--lr", type=float, default=3e-4)
@@ -168,37 +166,62 @@ def make_run(args) -> RunConfig:
                      fidelity_every=args.fidelity_every)
 
 
-_LHS_FLAGS = {
-    "tpu": "--xla_tpu_enable_latency_hiding_scheduler=true",
-    "gpu": "--xla_gpu_enable_latency_hiding_scheduler=true",
-}
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache at one fixed place.
 
-
-def _enable_lhs(backend: str) -> None:
-    """Append the backend's latency-hiding-scheduler flag to XLA_FLAGS.
-
-    Must run before the first jax device use (XLA reads the env once); the
-    overlapped schedule produces the async windows, this flag makes the
-    backend scheduler actually stretch them over compute.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the cache
+    there and nothing is changed; otherwise it goes to ``.jax_cache/`` at
+    the checkout root.  The path is part of each entry's key, so it never
+    depends on a pid, a time or a temporary directory.  Call it before the
+    first compile: JAX decides once per process whether the cache is used.
     """
-    import os
-
-    flag = _LHS_FLAGS[backend]
-    cur = os.environ.get("XLA_FLAGS", "")
-    if flag not in cur:
-        os.environ["XLA_FLAGS"] = f"{cur} {flag}".strip()
-        print(f"XLA_FLAGS += {flag}", flush=True)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
-def main(argv=None):
+@dataclasses.dataclass
+class TrainResult:
+    """What one :func:`main` run observed, for callers that check it."""
+
+    loss: float                  # last step's loss (nan when nothing ran)
+    losses: dict[int, float]     # loss of every logged step
+    compile_s: float | None      # ahead-of-time compile of the step program
+    first_step_s: float | None   # first executed step (one-time warm-up)
+    run_s: float                 # the steps after the first, end to end
+    n_run: int                   # how many steps run_s covers
+    custom_calls: int            # Pallas kernels (tpu_custom_call) in the step
+    n_devices: int               # devices of the mesh
+    state_bytes: dict[int, int]  # train-state bytes resident per device id
+    peak_bytes: dict[int, int]   # peak_bytes_in_use per device id, where known
+
+
+def _state_bytes(tree) -> dict[int, int]:
+    out: dict[int, int] = {}
+    for leaf in jax.tree.leaves(tree):
+        for sh in leaf.addressable_shards:
+            out[sh.device.id] = out.get(sh.device.id, 0) + sh.data.nbytes
+    return out
+
+
+def _peak_bytes(mesh) -> dict[int, int]:
+    out = {}
+    for d in mesh.devices.flat:
+        stats = d.memory_stats() or {}
+        if "peak_bytes_in_use" in stats:
+            out[d.id] = int(stats["peak_bytes_in_use"])
+    return out
+
+
+def main(argv=None) -> TrainResult:
     args = build_args(argv)
-    if args.xla_lhs:
-        _enable_lhs(args.xla_lhs)
+    use_compile_cache()
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
     if args.moe_a2a:
-        import dataclasses
         if cfg.moe_impl != "ep_a2a" or not cfg.n_experts:
             raise SystemExit(f"--moe-a2a: {cfg.name} has no ep_a2a MoE "
                              "dispatch to compress")
@@ -266,12 +289,25 @@ def main(argv=None):
         host = {k: float(v) for k, v in m.items()}
         return (host.pop("loss"), host.pop("gnorm"), host.pop("lr"), host)
 
-    # the first executed step pays tracing + XLA compilation; timing it with
-    # the rest would fold the compile into every throughput number, so block
-    # on it separately and start the run clock after it completes.
+    # compile the step ahead of time: the compile is set-up, not a step,
+    # and the compiled program says how many Pallas kernels it launches
+    compile_s, custom_calls, step_exe = None, 0, bundle.fn
+    if start < args.steps:
+        t_c = time.time()
+        step_exe = bundle.fn.lower(chunks, states, opt, jnp.int32(start),
+                                   batch_fn(jnp.int32(start))).compile()
+        compile_s = time.time() - t_c
+        custom_calls = step_exe.as_text().count(
+            'custom_call_target="tpu_custom_call"')
+        print(f"compiled step in {compile_s:.1f}s "
+              f"({custom_calls} kernel custom calls)", flush=True)
+
+    # the first executed step pays one-time warm-up; block on it separately
+    # and start the run clock after it completes.
     peak_err = 0.0
     step_s: list[float] = []
-    compile_s = None
+    losses: dict[int, float] = {}
+    first_s = None
     probe_compiled = False
     fid_every = run.fidelity_every
     t_run = t0 = time.time()
@@ -286,19 +322,19 @@ def main(argv=None):
         # bit- and launch-identical to a probe-free run
         probe_step = (fid_every > 0
                       and step % fid_every == fid_every - 1)
-        step_fn = bundle.probe_fn if probe_step else bundle.fn
+        step_fn = bundle.probe_fn if probe_step else step_exe
         chunks, states, opt, m = step_fn(chunks, states, opt, jnp.int32(step), batch)
         log_step = step % args.log_every == 0 or step == args.steps - 1
         sink_step = sink is not None and (
             step % metrics_every == 0 or step == args.steps - 1)
-        timed = sink is not None or trace is not None or compile_s is None
+        timed = sink is not None or trace is not None or first_s is None
         if timed:
             jax.block_until_ready(m["loss"])
             dt = time.time() - t_step
-            if compile_s is None:
-                compile_s = dt
+            if first_s is None:
+                first_s = dt
                 t_run = time.time()
-                print(f"compiled + step {step} in {compile_s:.1f}s", flush=True)
+                print(f"first step {step} in {first_s:.1f}s", flush=True)
             elif probe_step and not probe_compiled:
                 probe_compiled = True  # first probe pays its own compile
             else:
@@ -307,6 +343,7 @@ def main(argv=None):
             trace.maybe_stop(step)
         if log_step or sink_step or (probe_step and sink is not None):
             loss, gnorm, lr, extra_m = scalars(m)
+            losses[step] = loss
             fid_m = {k: extra_m.pop(k) for k in list(extra_m)
                      if k.startswith("fidelity/") or "/fid_" in k}
             peak_err = max(peak_err, extra_m.get("err_norm", 0.0))
@@ -318,9 +355,9 @@ def main(argv=None):
                           metrics=extra_m,
                           groups_inflight=bundle.helpers["groups_inflight"])
             if log_step:
-                # post-compile throughput: the first executed step is the
-                # compile step and is excluded from the clock
-                n_run = step - start if compile_s is not None else step - start + 1
+                # post-warm-up throughput: the first executed step is
+                # excluded from the clock
+                n_run = step - start if first_s is not None else step - start + 1
                 tok_s = (n_run * args.global_batch * args.seq_len
                          / max(time.time() - t_run, 1e-9))
                 extra = (f" err_norm={extra_m['err_norm']:.3e}"
@@ -341,15 +378,16 @@ def main(argv=None):
         if sink is not None:
             sink.close()
         print("nothing to do (restored step >= --steps)")
-        return float("nan")
+        return TrainResult(float("nan"), {}, None, None, 0.0, 0, 0,
+                           int(mesh.devices.size), {}, {})
     jax.block_until_ready(m["loss"])
     n_steps = args.steps - start
-    n_run = max(n_steps - 1, 0)  # post-compile steps
+    n_run = max(n_steps - 1, 0)  # steps after the first
     run_dt = time.time() - t_run
     tok_s = n_run * args.global_batch * args.seq_len / max(run_dt, 1e-9)
     print(f"done: {n_steps} steps in {time.time()-t0:.1f}s "
-          f"(compile {compile_s:.1f}s + run {run_dt:.1f}s, "
-          f"{tok_s:,.0f} tok/s post-compile)", flush=True)
+          f"(compile {compile_s:.1f}s, first step {first_s:.1f}s, "
+          f"run {run_dt:.1f}s, {tok_s:,.0f} tok/s post-compile)", flush=True)
     if sink is not None:
         sink.summary(
             steps=n_steps, compile_s=compile_s,
@@ -361,7 +399,12 @@ def main(argv=None):
         )
         sink.close()
         print(f"telemetry: {sink.path}", flush=True)
-    return float(m["loss"])
+    return TrainResult(
+        loss=float(m["loss"]), losses=losses, compile_s=compile_s,
+        first_step_s=first_s, run_s=run_dt, n_run=n_run,
+        custom_calls=custom_calls, n_devices=int(mesh.devices.size),
+        state_bytes=_state_bytes((chunks, states, opt)),
+        peak_bytes=_peak_bytes(mesh))
 
 
 if __name__ == "__main__":

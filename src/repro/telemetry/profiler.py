@@ -11,12 +11,10 @@ Two complementary mechanisms:
   parses annotated modules identically (pinned in tests/test_metrics.py).
 * :class:`TraceSession` + :func:`parse_window` — host-side capture of a
   ``jax.profiler.start_trace`` dir for a step window (``--profile-steps
-  N:M`` in launch/train.py).  Capture failures degrade to a warning: a
-  missing profiler backend must never kill a training run.
+  N:M`` in launch/train.py).  A failed start or stop raises: a run asked
+  for a trace and must not exit 0 without one.
 """
 from __future__ import annotations
-
-import warnings
 
 import jax
 
@@ -64,14 +62,10 @@ class TraceSession:
     def maybe_start(self, step: int) -> None:
         if self.active or step != self.lo:
             return
-        try:
-            jax.profiler.start_trace(self.trace_dir)
-            self.active = True
-            print(f"profiler: tracing steps {self.lo}..{self.hi} "
-                  f"-> {self.trace_dir}", flush=True)
-        except Exception as e:  # missing backend, busy profiler, ...
-            warnings.warn(f"profiler start failed ({e}); continuing untraced")
-            self.lo = -1  # don't retry every step
+        jax.profiler.start_trace(self.trace_dir)
+        self.active = True
+        print(f"profiler: tracing steps {self.lo}..{self.hi} "
+              f"-> {self.trace_dir}", flush=True)
 
     def maybe_stop(self, step: int) -> None:
         if self.active and step >= self.hi:
@@ -81,8 +75,5 @@ class TraceSession:
         if not self.active:
             return
         self.active = False
-        try:
-            jax.profiler.stop_trace()
-            print(f"profiler: trace written to {self.trace_dir}", flush=True)
-        except Exception as e:
-            warnings.warn(f"profiler stop failed ({e})")
+        jax.profiler.stop_trace()
+        print(f"profiler: trace written to {self.trace_dir}", flush=True)

@@ -357,12 +357,13 @@ def test_train_cli_policy_kernels_end_to_end(capsys):
     """launch/train.py --policy 'body=loco4+kernels' runs the bucketed,
     kernel-dispatched path for real (acceptance criterion)."""
     from repro.launch import train as T
-    loss = T.main([
+    res = T.main([
         "--arch", "llama2-400m", "--reduced", "--steps", "2",
         "--seq-len", "16", "--global-batch", "4", "--dp", "2", "--tp", "1",
         "--sync", "loco", "--bucket-mb", "0.0625",
         "--policy", "body=loco4+kernels,min=4096", "--log-every", "1"])
-    assert np.isfinite(loss)
+    assert np.isfinite(res.loss)
+    assert sorted(res.losses) == [0, 1]
     out = capsys.readouterr().out
     assert "wire/step/device" in out  # plan report printed
 

@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import buckets as BK
 from repro.core.comm import (all_gather_flat, all_to_all_chunks, dist_sync,
@@ -74,9 +74,7 @@ def test_all_to_all_chunks_identity(mesh22):
     """Row i of the exchange lands on peer i, in rank order."""
     def body(x):
         r = jax.lax.axis_index("data")
-        rows = jnp.stack([r * 10 + jnp.arange(2, dtype=jnp.int32)
-                          for _ in range(2)])  # (2, 2): my payload for each peer
-        rows = rows + jnp.array([[0], [100]], jnp.int32) * 0  # keep shape
+        # (2, 2): my payload row for each peer
         rows = jnp.stack([r * 10 + 0 * jnp.arange(2), r * 10 + jnp.arange(2)]).astype(jnp.int32)
         recv = all_to_all_chunks(rows, ("data",))
         return recv[None]
@@ -86,8 +84,9 @@ def test_all_to_all_chunks_identity(mesh22):
     out = fn(jnp.zeros((2, 1), jnp.int32))
     # device d receives row j = peer j's chunk-for-d
     assert out.shape == (2, 2, 2)
-    assert out[0, 1, 0] == 10  # peer 1's payload row 0 as received by dev 0... row semantics
-    assert out[1, 0, 1] == 1   # peer 0's row for dev 1 is [0*10+arange][1] = 1
+    host = np.asarray(out)
+    assert host[0, 1, 0] == 10  # peer 1's payload row 0 as received by dev 0... row semantics
+    assert host[1, 0, 1] == 1   # peer 0's row for dev 1 is [0*10+arange][1] = 1
 
 
 def test_gather_fp_grad_is_mean(mesh22):
@@ -615,11 +614,11 @@ def test_cadence_every2_accumulates(mesh22):
                                   jnp.int32(0))
     assert not np.any(np.asarray(sh0))
     codec = codec_lib.get_codec(cfg)
+    acc_host = np.asarray(st_acc.astype(jnp.float32))
     for i in range(N):
         want = codec.state_encode(g0[i] + codec.state_decode(st0[i]))
         np.testing.assert_array_equal(
-            np.asarray(st_acc[i].astype(jnp.float32)),
-            np.asarray(want.astype(jnp.float32)))
+            acc_host[i], np.asarray(want.astype(jnp.float32)))
 
     sh1, st1 = _dist_sync_step(mesh22, ("data",), cfg, g1, st_acc,
                                jnp.int32(1))
@@ -649,7 +648,9 @@ def test_cadence_single_trace_across_period(mesh22):
         body, mesh=mesh22, in_specs=(P("data"), P("data"), P()),
         out_specs=(P(None), P("data")), check_vma=False))
     g = jax.random.normal(jax.random.PRNGKey(37), (N, n)) * 1e-3
-    st = jnp.stack([init_state(cfg, n) for _ in range(N)])
+    # place st as the step returns it, so every call sees one abstract value
+    st = jax.device_put(jnp.stack([init_state(cfg, n) for _ in range(N)]),
+                        NamedSharding(mesh22, P("data")))
     outs = []
     for s in range(4):
         full, st = fn(g, st, jnp.int32(s))

@@ -95,3 +95,28 @@ def test_multipod_mesh_trains(mesh_pod):
                                             quant=QuantConfig(mode="block")), steps=6)
     assert np.isfinite(losses).all()
     assert losses[-1] < losses[0]
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_location(monkeypatch, tmp_path, from_env):
+    """The persistent compile cache stays where JAX_COMPILATION_CACHE_DIR
+    puts it (nothing is set), and otherwise goes to ``.jax_cache/`` at the
+    checkout root -- a fixed path, so a later run finds it again."""
+    from pathlib import Path
+
+    from repro.launch import train as T
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert T.use_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+            assert T.use_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+            assert T.use_compile_cache() == want  # idempotent
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
