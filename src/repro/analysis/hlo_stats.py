@@ -33,7 +33,6 @@ Validated against cost_analysis on loop-free modules (test_analysis.py).
 from __future__ import annotations
 
 import dataclasses
-import math
 import re
 
 _DTYPE_BYTES = {
@@ -236,12 +235,7 @@ def _analyze_comp(name: str, comps: dict, memo: dict) -> HloStats:
 
 
 def _instr_cost(ins: Instr, shape_of: dict) -> tuple[float, float]:
-    """(flops, hbm_bytes) for one non-control, non-collective instruction.
-
-    Shared by the roofline accumulator (:func:`_analyze_comp`) and the
-    overlap estimator (:func:`_overlap_comp`) so both charge identical
-    per-instruction costs.
-    """
+    """(flops, hbm_bytes) for one non-control, non-collective instruction."""
     op = ins.opcode
     fl = 0.0
     # ---- flops: dot --------------------------------------------------------
@@ -295,219 +289,3 @@ def collective_launches(hlo_text: str) -> dict[str, float]:
     Validated against hand-countable modules in tests/test_analysis.py.
     """
     return dict(analyze(hlo_text).coll_counts)
-
-
-# ---------------------------------------------------------------------------
-# compute/collective overlap estimation (DESIGN.md §14)
-# ---------------------------------------------------------------------------
-#
-# XLA emits asynchronous collectives as `-start`/`-done` instruction pairs;
-# everything scheduled between the pair can execute while the wire transfer
-# is in flight.  Walking each computation IN PROGRAM ORDER and accumulating
-# the roofline compute time (max(flops/PEAK_FLOPS, bytes/HBM_BW)) of the
-# instructions inside each open start..done window gives a static estimate
-# of how much of each collective's wire time is hideable:
-#
-#     hidden = sum over async collectives of min(t_wire, t_compute_in_window)
-#
-# Synchronous collectives (no -start form) contribute wire time with zero
-# hidden.  The fraction hidden/total is the schedule's overlap headroom --
-# the number hierarchical/coalesced exchange is trying to raise.  Times use
-# the same TPU-v5e roofline constants as analysis/roofline, so this is a
-# *model* estimate (consistent across configs), not a measurement.
-
-@dataclasses.dataclass
-class OverlapStats:
-    """Static overlap estimate for one compiled module (trip-weighted)."""
-
-    collective_s: float = 0.0   # total wire time of all collectives
-    hidden_s: float = 0.0       # part hideable under same-window compute
-    compute_s: float = 0.0      # total non-collective roofline time
-    n_async: float = 0.0        # collectives emitted as -start/-done pairs
-    n_sync: float = 0.0         # collectives emitted synchronously
-
-    @property
-    def exposed_s(self) -> float:
-        return max(0.0, self.collective_s - self.hidden_s)
-
-    @property
-    def overlap_fraction(self) -> float:
-        """Fraction of collective wire time hideable under compute (0..1)."""
-        return self.hidden_s / self.collective_s if self.collective_s else 0.0
-
-    def add(self, other: "OverlapStats", w: float):
-        self.collective_s += w * other.collective_s
-        self.hidden_s += w * other.hidden_s
-        self.compute_s += w * other.compute_s
-        self.n_async += w * other.n_async
-        self.n_sync += w * other.n_sync
-
-    def to_json(self) -> dict:
-        return {"collective_s": self.collective_s, "hidden_s": self.hidden_s,
-                "exposed_s": self.exposed_s, "compute_s": self.compute_s,
-                "overlap_fraction": self.overlap_fraction,
-                "n_async": self.n_async, "n_sync": self.n_sync}
-
-
-@dataclasses.dataclass
-class _PipeEnds:
-    """Async windows that CROSS a computation boundary.
-
-    A software-pipelined schedule (the overlap schedule of DESIGN.md §15,
-    or XLA's own collective pipelining) opens a ``*-start`` in one loop
-    iteration and closes it with the ``*-done`` at the top of the next, so
-    neither end of the window is visible to a single program-order walk of
-    the body.  ``opens`` records the dangling starts as
-    ``(wire_s, tail_compute_s)`` pairs (compute accumulated from the start
-    to the end of the computation); ``dones`` records the unmatched dones'
-    prefix compute (accumulated from the top of the computation to the
-    done).  The ``while`` handler FIFO-pairs a body's opens with its dones
-    to credit the iteration-crossing windows, threads the first done to
-    the caller's open windows and re-opens the last start in the caller.
-    """
-
-    opens: list = dataclasses.field(default_factory=list)
-    dones: list = dataclasses.field(default_factory=list)
-
-
-def _overlap_comp(name: str, comps: dict, memo: dict,
-                  consts: tuple[float, float, float]
-                  ) -> tuple[OverlapStats, _PipeEnds]:
-    peak_flops, hbm_bw, ici_bw = consts
-    if name in memo:
-        return memo[name]
-    st, ends = OverlapStats(), _PipeEnds()
-    memo[name] = (st, ends)  # placeholder to guard recursion
-    shape_of = {i.name: i.result_type for i in comps[name]}
-    # open async windows: start-instr name -> [wire_s, compute_s since start]
-    windows: dict[str, list[float]] = {}
-    prefix = 0.0  # compute since the top of this computation
-
-    def add_compute(t: float) -> None:
-        nonlocal prefix
-        st.compute_s += t
-        prefix += t
-        for w in windows.values():
-            w[1] += t
-
-    def close_window(key: str) -> None:
-        w = windows.pop(key)
-        st.hidden_s += min(w[0], w[1])
-
-    def consume_ends(child_ends: _PipeEnds, trips: float,
-                     total_compute: float) -> None:
-        """Account a child computation's boundary-crossing windows.
-
-        For each (open, done) FIFO pair the window spans one iteration
-        boundary: in flight over the open's tail compute plus the done's
-        prefix compute, once per crossing (``trips - 1``).  The first
-        iteration's done instead closes the oldest window open HERE (the
-        window it actually completes, having accrued its prefix on top);
-        the last iteration's start has its done after the loop, so it
-        re-opens in this computation with only its tail accrued.  Windows
-        open here that the child does NOT close span the whole child:
-        they accrue ``total_compute`` (= trips x body compute).  Unpaired
-        opens (done elided entirely) still hide their tail each full
-        iteration.  call/conditional use trips=1: pass-through.
-        """
-        npair = min(len(child_ends.opens), len(child_ends.dones))
-        for i, (wire, tail) in enumerate(child_ends.opens):
-            cross = tail + child_ends.dones[i] if i < npair else tail
-            st.hidden_s += max(0.0, trips - 1) * min(wire, cross)
-        for p in child_ends.dones:
-            # iteration 0's done targets a window opened before the child
-            if windows:
-                w = windows.pop(next(iter(windows)))
-                st.hidden_s += min(w[0], w[1] + p)
-            else:
-                ends.dones.append(prefix + p)
-        add_compute(total_compute)  # surviving pre-child windows span it
-        for i, (wire, tail) in enumerate(child_ends.opens):
-            windows[f"{name}#pipe{len(windows)}#{i}"] = [wire, tail]
-
-    for ins in comps[name]:
-        op = ins.opcode
-        base = op[:-len("-start")] if op.endswith("-start") else op
-        if op.endswith("-done"):
-            opnds = _OPERAND_RE.findall(ins.rest)
-            if opnds and opnds[0] in windows:
-                close_window(opnds[0])
-            elif windows:
-                # operand is a tuple-element of a while/call result: the
-                # matching start crossed in via consume_ends -- FIFO.
-                close_window(next(iter(windows)))
-            else:
-                ends.dones.append(prefix)
-            continue
-        if base in _COLLECTIVES:
-            t = _collective_wire(base, ins.result_type, ins.rest) / ici_bw
-            st.collective_s += t
-            if op.endswith("-start"):
-                windows[ins.name] = [t, 0.0]
-                st.n_async += 1
-            else:
-                st.n_sync += 1
-            continue
-        if op == "while":
-            mb = re.search(r"body=%?([\w.\-]+)", ins.rest)
-            mc = re.search(r"condition=%?([\w.\-]+)", ins.rest)
-            if mb and mc and mb.group(1) in comps:
-                trips = _trip_count(comps[mc.group(1)]) if mc.group(1) in comps else 1
-                child, cends = _overlap_comp(mb.group(1), comps, memo, consts)
-                st.add(child, trips)
-                st.compute_s -= trips * child.compute_s  # consume_ends re-adds
-                consume_ends(cends, trips, trips * child.compute_s)
-            continue
-        if op == "call":
-            mt = re.search(r"to_apply=%?([\w.\-]+)", ins.rest)
-            if mt and mt.group(1) in comps:
-                child, cends = _overlap_comp(mt.group(1), comps, memo, consts)
-                st.add(child, 1.0)
-                st.compute_s -= child.compute_s
-                consume_ends(cends, 1.0, child.compute_s)
-            continue
-        if op == "conditional":
-            for mt in re.finditer(r"(?:branch_computations=\{|true_computation=|"
-                                  r"false_computation=)%?([\w.\-]+)", ins.rest):
-                if mt.group(1) in comps:
-                    child, cends = _overlap_comp(mt.group(1), comps, memo, consts)
-                    st.add(child, 1.0)
-                    st.compute_s -= child.compute_s
-                    consume_ends(cends, 1.0, child.compute_s)
-            continue
-        if op in _SKIP_OPS:
-            continue
-        fl, b = _instr_cost(ins, shape_of)
-        add_compute(max(fl / peak_flops, b / hbm_bw))
-    # windows never closed inside this computation: their done (if any)
-    # lives in a caller or a later iteration -- export, don't credit here.
-    for w in windows.values():
-        ends.opens.append((w[0], w[1]))
-    memo[name] = (st, ends)
-    return st, ends
-
-
-def overlap_stats(hlo_text: str, *, peak_flops: float | None = None,
-                  hbm_bw: float | None = None,
-                  ici_bw: float | None = None) -> OverlapStats:
-    """Compute/collective overlap estimate for a compiled HLO module.
-
-    Defaults to the TPU-v5e roofline constants (analysis/roofline).  Pass
-    explicit bandwidths to model other parts (tests use 1.0 each so times
-    equal raw flops/bytes).
-    """
-    if peak_flops is None or hbm_bw is None or ici_bw is None:
-        from repro.analysis import roofline as _RL
-        peak_flops = _RL.PEAK_FLOPS if peak_flops is None else peak_flops
-        hbm_bw = _RL.HBM_BW if hbm_bw is None else hbm_bw
-        ici_bw = _RL.ICI_BW if ici_bw is None else ici_bw
-    comps, entry = parse_computations(hlo_text)
-    memo: dict = {}
-    st, ends = _overlap_comp(entry, comps, memo, (peak_flops, hbm_bw, ici_bw))
-    res = OverlapStats()
-    res.add(st, 1.0)
-    # windows still dangling at ENTRY's end (done truly elided): credit
-    # whatever compute accumulated while they were in flight.
-    for wire, acc in ends.opens:
-        res.hidden_s += min(wire, acc)
-    return res

@@ -69,24 +69,20 @@ def roofline_table(recs, mesh="16x16"):
 
 def collective_table(recs, mesh="16x16", shape="train_4k"):
     lines = [
-        "| arch | all-gather | all-reduce | all-to-all | reduce-scatter | total wire "
-        "| overlap |",
-        "|---|---|---|---|---|---|---|",
+        "| arch | all-gather | all-reduce | all-to-all | reduce-scatter "
+        "| total wire |",
+        "|---|---|---|---|---|---|",
     ]
     for a in ARCH_ORDER:
         r = recs.get((a, shape, mesh))
         if not r or r["status"] != "ok":
             continue
         bk = r["collectives"]["bytes_by_kind"]
-        ov = r.get("overlap")
-        if ov:  # nested {overlapped, legacy} since the PR 7 scheduler
-            ov = ov.get("overlapped", ov)
-        ovs = f"{ov['overlap_fraction']:.0%}" if ov else "n/a"
         lines.append(
             f"| {a} | " + " | ".join(
                 f"{bk.get(k, 0)/2**30:.2f}" for k in
                 ("all-gather", "all-reduce", "all-to-all", "reduce-scatter"))
-            + f" | {r['collectives']['wire_bytes']/2**30:.2f} GiB | {ovs} |")
+            + f" | {r['collectives']['wire_bytes']/2**30:.2f} GiB |")
     return "\n".join(lines)
 
 

@@ -25,6 +25,7 @@ from repro.core.comm import (_fit_rows, all_gather_flat, axis_size,
                              dist_sync, dist_sync_buckets, dist_sync_runs,
                              psum_scatter_flat)
 from repro.core.loco import SyncConfig
+from repro.telemetry import profiler as PROF
 
 
 def _reject_stochastic_rounding(cfg: SyncConfig) -> None:
@@ -54,6 +55,14 @@ def _as_step(step) -> jax.Array:
     return jnp.float32(0.0) if step is None else jnp.asarray(step, jnp.float32)
 
 
+def _gather(w_chunk: jax.Array, dp_axes: tuple[str, ...]) -> jax.Array:
+    """The forward weight all-gather of every hijack below, under the
+    ``loco/gather`` scope.  The scope sits here and not on
+    ``comm.all_gather_flat``, which the exchange also calls."""
+    with PROF.phase("gather"):
+        return all_gather_flat(w_chunk, dp_axes)
+
+
 @lru_cache(maxsize=None)
 def _make_gather(cfg: SyncConfig, dp_axes: tuple[str, ...]):
     """Build (and cache) the custom_vjp gather for a given static config."""
@@ -62,10 +71,10 @@ def _make_gather(cfg: SyncConfig, dp_axes: tuple[str, ...]):
     @jax.custom_vjp
     def gather(w_chunk: jax.Array, state: jax.Array,
                step: jax.Array) -> jax.Array:
-        return all_gather_flat(w_chunk, dp_axes)
+        return _gather(w_chunk, dp_axes)
 
     def fwd(w_chunk, state, step):
-        return all_gather_flat(w_chunk, dp_axes), (state, step)
+        return _gather(w_chunk, dp_axes), (state, step)
 
     def bwd(res, g_full):
         state, step = res
@@ -124,10 +133,10 @@ def _make_bucketed_gather(plan: ParamPlan, dp_axes: tuple[str, ...],
     @jax.custom_vjp
     def gather(w_chunk: jax.Array, states: tuple,
                step: jax.Array) -> jax.Array:
-        return all_gather_flat(w_chunk, dp_axes)
+        return _gather(w_chunk, dp_axes)
 
     def fwd(w_chunk, states, step):
-        return all_gather_flat(w_chunk, dp_axes), (states, step)
+        return _gather(w_chunk, dp_axes), (states, step)
 
     def bwd(res, g_full):
         states, step = res
@@ -190,10 +199,10 @@ def _make_run_gather(plan: ParamPlan, dp_axes: tuple[str, ...],
     @jax.custom_vjp
     def gather(w_chunk: jax.Array, run_states: tuple,
                step: jax.Array) -> jax.Array:
-        return all_gather_flat(w_chunk, dp_axes)
+        return _gather(w_chunk, dp_axes)
 
     def fwd(w_chunk, run_states, step):
-        return all_gather_flat(w_chunk, dp_axes), (run_states, step)
+        return _gather(w_chunk, dp_axes), (run_states, step)
 
     def bwd(res, g_full):
         run_states, step = res
@@ -256,10 +265,10 @@ def _make_gather_probe(cfg: SyncConfig, dp_axes: tuple[str, ...]):
     @jax.custom_vjp
     def gather(w_chunk: jax.Array, state: jax.Array, probe: jax.Array,
                step: jax.Array) -> jax.Array:
-        return all_gather_flat(w_chunk, dp_axes)
+        return _gather(w_chunk, dp_axes)
 
     def fwd(w_chunk, state, probe, step):
-        return all_gather_flat(w_chunk, dp_axes), (state, probe, step)
+        return _gather(w_chunk, dp_axes), (state, probe, step)
 
     def bwd(res, g_full):
         state, probe, step = res
@@ -286,10 +295,10 @@ def _make_bucketed_gather_probe(plan: ParamPlan, dp_axes: tuple[str, ...]):
     @jax.custom_vjp
     def gather(w_chunk: jax.Array, states: tuple, probe: jax.Array,
                step: jax.Array) -> jax.Array:
-        return all_gather_flat(w_chunk, dp_axes)
+        return _gather(w_chunk, dp_axes)
 
     def fwd(w_chunk, states, probe, step):
-        return all_gather_flat(w_chunk, dp_axes), (states, probe, step)
+        return _gather(w_chunk, dp_axes), (states, probe, step)
 
     def bwd(res, g_full):
         states, probe, step = res
@@ -321,10 +330,10 @@ def _make_run_gather_probe(plan: ParamPlan, dp_axes: tuple[str, ...]):
     @jax.custom_vjp
     def gather(w_chunk: jax.Array, run_states: tuple, probe: jax.Array,
                step: jax.Array) -> jax.Array:
-        return all_gather_flat(w_chunk, dp_axes)
+        return _gather(w_chunk, dp_axes)
 
     def fwd(w_chunk, run_states, probe, step):
-        return all_gather_flat(w_chunk, dp_axes), (run_states, probe, step)
+        return _gather(w_chunk, dp_axes), (run_states, probe, step)
 
     def bwd(res, g_full):
         run_states, probe, step = res
@@ -362,10 +371,10 @@ def _make_gather_fp(dp_axes: tuple[str, ...]):
 
     @jax.custom_vjp
     def gather(w_chunk):
-        return all_gather_flat(w_chunk, dp_axes)
+        return _gather(w_chunk, dp_axes)
 
     def fwd(w_chunk):
-        return all_gather_flat(w_chunk, dp_axes), None
+        return _gather(w_chunk, dp_axes), None
 
     def bwd(_, g_full):
         # bf16 wire (the "16-bit Adam" baseline of the paper); mean in f32.

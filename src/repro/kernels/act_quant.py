@@ -62,6 +62,7 @@ def act_encode(h: jax.Array, *, interpret: bool,
             jax.ShapeDtypeStruct((rows_total, 1), jnp.float32),
         ),
         interpret=interpret,
+        name="act_quant_encode",
     )(h)
     return q, s.reshape(rows_total)
 
@@ -83,4 +84,5 @@ def act_decode(q: jax.Array, scale: jax.Array, *, interpret: bool,
         out_specs=pl.BlockSpec((R, ACT_BLOCK), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_total, ACT_BLOCK), jnp.float32),
         interpret=interpret,
+        name="act_quant_decode",
     )(q, scale.reshape(rows_total, 1))

@@ -134,6 +134,7 @@ def fused_compress(
         ),
         out_shape=out_shapes,
         interpret=interpret,
+        name="loco_fused_compress",
     )(gm, em)
     return q.reshape(-1), s.reshape(n // QBLOCK), enew.reshape(n)
 
@@ -200,5 +201,6 @@ def dequant_mean(
         out_specs=pl.BlockSpec((R, QBLOCK), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_total, QBLOCK), jnp.float32),
         interpret=interpret,
+        name="loco_dequant_mean",
     )(pm, sm)
     return out.reshape(n_chunk)

@@ -82,5 +82,6 @@ def onebit_pack(
         ),
         out_shape=out_shapes,
         interpret=interpret,
+        name="onebit_sign_pack",
     )(hm, sm)
     return packed.reshape(n // SIGN_PACK), enew.reshape(n)

@@ -121,7 +121,6 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         n_dev = mesh.devices.size
         model_flops_dev = model_flops_global / n_dev
 
-        ov_rec: dict = HS.overlap_stats(hlo).to_json()
         fid_rec = None
         if shape.kind == "train" and bundle.probe_fn is not None:
             # predicted probe-step overhead (DESIGN.md §17): compile the
@@ -157,27 +156,6 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             _rep = WIRE.plan_report(bundle.helpers["plan"],
                                     pods=_topo.pods, wans=_topo.wans)
             wire_tiers = [t.record() for t in _rep.tiers]
-        if shape.kind == "train":
-            # report BOTH sync schedules (legacy flat vs backward-
-            # overlapped, DESIGN.md §15), not just whichever the primary
-            # module compiled with.  The second compile is skipped when
-            # the overlap schedule has nothing to pipeline (no bucket
-            # plan, or single-stage) -- the schedules then coincide.
-            import dataclasses as _dc
-            from repro.launch.steps import groups_inflight as _gi
-            this = "overlapped" if (run.coalesce and run.overlap) else "legacy"
-            other = "legacy" if this == "overlapped" else "overlapped"
-            depth = _gi(_dc.replace(run, coalesce=True, overlap=True),
-                        bundle.helpers["plan"], bundle.helpers["topo"])
-            if depth > 1:
-                alt = _dc.replace(run, coalesce=True,
-                                  overlap=(this == "legacy"))
-                alt_hlo = (make_train_step(cfg, alt, mesh, shape).fn
-                           .lower(*bundle.input_shapes).compile().as_text())
-                ov_rec = {this: ov_rec,
-                          other: HS.overlap_stats(alt_hlo).to_json()}
-            else:
-                ov_rec = {this: ov_rec, other: ov_rec}
 
         rec.update(
             status="ok",
@@ -200,7 +178,6 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             collectives=dict(counts={k: round(v) for k, v in st.coll_counts.items()},
                              bytes_by_kind={k: round(v) for k, v in st.coll_bytes.items()},
                              wire_bytes=round(st.wire_bytes)),
-            overlap=ov_rec,
             wire_tiers=wire_tiers,
             moe_a2a=moe_rec,
             fidelity=fid_rec,
@@ -224,16 +201,9 @@ def _emit(rec: dict, out_dir: str | None) -> dict:
     extra = ""
     if status == "ok":
         r = rec["roofline"]
-        ov = rec.get("overlap", {})
-        if "overlapped" in ov and "legacy" in ov:  # per-schedule (train)
-            ovs = (f"{ov['overlapped'].get('overlap_fraction', 0.0):.0%}"
-                   f"/{ov['legacy'].get('overlap_fraction', 0.0):.0%}")
-        else:
-            ovs = f"{ov.get('overlap_fraction', 0.0):.0%}"
         extra = (f" compile={rec['compile_s']}s peak={rec['memory']['peak_bytes']/2**30:.2f}GiB "
                  f"dom={r['dominant']} c/m/n={r['compute_s']:.4f}/{r['memory_s']:.4f}/"
-                 f"{r['collective_s']:.4f}s"
-                 f" ovl={ovs}")
+                 f"{r['collective_s']:.4f}s")
         if rec.get("wire_tiers"):
             # effective/capacity MiB per tier at its cadence (DESIGN.md §16)
             extra += " tiers=" + ",".join(
@@ -286,8 +256,7 @@ def main():
                          "fid= column (DESIGN.md §17)")
     ap.add_argument("--no-overlap", dest="overlap", action="store_false",
                     help="compile the primary train module on the legacy "
-                         "flat schedule (the overlap record still reports "
-                         "both schedules)")
+                         "flat schedule")
     ap.add_argument("--out", default="experiments/dryrun")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()

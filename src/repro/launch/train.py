@@ -15,6 +15,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import os
+import re
 import tempfile
 import time
 from pathlib import Path
@@ -170,14 +171,25 @@ def use_compile_cache() -> str:
     """Keep JAX's persistent compilation cache at one fixed place.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the cache
-    there and nothing is changed; otherwise it goes to ``.jax_cache/`` at
-    the checkout root.  The path is part of each entry's key, so it never
-    depends on a pid, a time or a temporary directory.  Call it before the
-    first compile: JAX decides once per process whether the cache is used.
+    there and the directory is left as it is; otherwise it goes to
+    ``.jax_cache/`` at the checkout root.  The path is part of each entry's
+    key, so it never depends on a pid, a time or a temporary directory.
+    Call it before the first compile: JAX decides once per process whether
+    the cache is used.
+
+    The HLO's metadata (the ``loco/*`` and ``model/*`` scopes of
+    ``telemetry/profiler``, source locations) is part of the key: a trace
+    is read by those names, and an entry compiled from the same program
+    with other scopes would carry the wrong ones.  Source files are named
+    from the checkout root, so a checkout elsewhere finds the same entries.
     """
+    root = Path(__file__).resolve().parents[3]
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(f"{root}{os.sep}"))
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
-        path = str(Path(__file__).resolve().parents[3] / ".jax_cache")
+        path = str(root / ".jax_cache")
         jax.config.update("jax_compilation_cache_dir", path)
     return path
 
@@ -315,30 +327,32 @@ def main(argv=None) -> TrainResult:
     for step in range(start, args.steps):
         if trace is not None:
             trace.maybe_start(step)
-        t_step = time.time()
-        batch = batch_fn(jnp.int32(step))
-        # fidelity-probe dispatch (DESIGN.md §17): a host-side select of
-        # the separately-compiled probe variant — the normal step stays
-        # bit- and launch-identical to a probe-free run
-        probe_step = (fid_every > 0
-                      and step % fid_every == fid_every - 1)
-        step_fn = bundle.probe_fn if probe_step else step_exe
-        chunks, states, opt, m = step_fn(chunks, states, opt, jnp.int32(step), batch)
-        log_step = step % args.log_every == 0 or step == args.steps - 1
-        sink_step = sink is not None and (
-            step % metrics_every == 0 or step == args.steps - 1)
-        timed = sink is not None or trace is not None or first_s is None
-        if timed:
-            jax.block_until_ready(m["loss"])
-            dt = time.time() - t_step
-            if first_s is None:
-                first_s = dt
-                t_run = time.time()
-                print(f"first step {step} in {first_s:.1f}s", flush=True)
-            elif probe_step and not probe_compiled:
-                probe_compiled = True  # first probe pays its own compile
-            else:
-                step_s.append(dt)
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            t_step = time.time()
+            batch = batch_fn(jnp.int32(step))
+            # fidelity-probe dispatch (DESIGN.md §17): a host-side select of
+            # the separately-compiled probe variant — the normal step stays
+            # bit- and launch-identical to a probe-free run
+            probe_step = (fid_every > 0
+                          and step % fid_every == fid_every - 1)
+            step_fn = bundle.probe_fn if probe_step else step_exe
+            chunks, states, opt, m = step_fn(chunks, states, opt,
+                                             jnp.int32(step), batch)
+            log_step = step % args.log_every == 0 or step == args.steps - 1
+            sink_step = sink is not None and (
+                step % metrics_every == 0 or step == args.steps - 1)
+            timed = sink is not None or trace is not None or first_s is None
+            if timed:
+                jax.block_until_ready(m["loss"])
+                dt = time.time() - t_step
+                if first_s is None:
+                    first_s = dt
+                    t_run = time.time()
+                    print(f"first step {step} in {first_s:.1f}s", flush=True)
+                elif probe_step and not probe_compiled:
+                    probe_compiled = True  # first probe pays its own compile
+                else:
+                    step_s.append(dt)
         if trace is not None:
             trace.maybe_stop(step)
         if log_step or sink_step or (probe_step and sink is not None):

@@ -24,6 +24,7 @@ from repro.models import common as C
 from repro.models import moe as MOE
 from repro.models import ssm as SSM
 from repro.models.common import HeadLayout, KVCache
+from repro.telemetry import profiler as PROF
 
 LOCO_MIN_NUMEL = 2**16  # smaller tensors sync in bf16 (DESIGN.md §4)
 
@@ -195,65 +196,68 @@ def attention_block(p, x, cfg: ArchConfig, lay: HeadLayout, layer_idx, positions
     sp: x is the (B, S/TP, d) sequence shard; norm runs on the shard, the
     block gathers to full S for attention and returns a scattered shard
     (Megatron sequence parallelism)."""
-    h = C.norm(cfg.norm, x, p[prefix + "norm1"])
-    h = C.sp_gather(h, sp) if sp else h
-    B, S, d = h.shape
-    q, k, v = _qkv(p, h, lay, cfg, positions, prefix)
-    window = _layer_window(cfg, layer_idx)
-    kv_map = lay.kv_map()
+    with PROF.layer("attention"):
+        h = C.norm(cfg.norm, x, p[prefix + "norm1"])
+        h = C.sp_gather(h, sp) if sp else h
+        B, S, d = h.shape
+        q, k, v = _qkv(p, h, lay, cfg, positions, prefix)
+        window = _layer_window(cfg, layer_idx)
+        kv_map = lay.kv_map()
 
-    cp = C.cp_degree(lay)
+        cp = C.cp_degree(lay)
 
-    if cache is None:
-        kq, vq = C.expand_kv(k, kv_map), C.expand_kv(v, kv_map)
-        out = C.blockwise_attention(
-            q, kq, vq, positions, positions,
-            causal=True, window=window, softcap=cfg.attn_softcap,
-        )
-        new_cache = None
-    elif S > 1:
-        # prefill into the cache; attention over the in-flight k/v directly
-        # (the cache was empty), then persist -- window-sharded when kv heads
-        # are TP-replicated (see common.py cp_* docs).
-        kq, vq = C.expand_kv(k, kv_map), C.expand_kv(v, kv_map)
-        out = C.blockwise_attention(
-            q, kq, vq, positions, positions,
-            causal=True, window=window, softcap=cfg.attn_softcap,
-        )
-        if cp > 1:
-            new_cache = C.build_cp_cache(k, v, cache.k.shape[1], cp,
-                                         dtype=cache.k.dtype)
-        else:
-            new_cache = cache.append(k, v, positions[0])
-    else:
-        # single-token decode
-        if cp > 1:
-            new_cache = C.cp_append(cache, k, v, positions[0], cp)
-            out = C.cp_decode_attention(
-                q, new_cache, lay.kv_map_global(), positions,
-                window=window, softcap=cfg.attn_softcap)
-        else:
-            new_cache = cache.append(k, v, positions[0])
-            kq = C.expand_kv(new_cache.k, kv_map)
-            vq = C.expand_kv(new_cache.v, kv_map)
+        if cache is None:
+            kq, vq = C.expand_kv(k, kv_map), C.expand_kv(v, kv_map)
             out = C.blockwise_attention(
-                q, kq, vq, positions, new_cache.pos,
+                q, kq, vq, positions, positions,
                 causal=True, window=window, softcap=cfg.attn_softcap,
             )
-    out = out.reshape(B, S, lay.hl * lay.head_dim)
-    return C.row_linear(out, p[prefix + "wo"], sp=sp), new_cache
+            new_cache = None
+        elif S > 1:
+            # prefill into the cache; attention over the in-flight k/v
+            # directly (the cache was empty), then persist -- window-sharded
+            # when kv heads are TP-replicated (see common.py cp_* docs).
+            kq, vq = C.expand_kv(k, kv_map), C.expand_kv(v, kv_map)
+            out = C.blockwise_attention(
+                q, kq, vq, positions, positions,
+                causal=True, window=window, softcap=cfg.attn_softcap,
+            )
+            if cp > 1:
+                new_cache = C.build_cp_cache(k, v, cache.k.shape[1], cp,
+                                             dtype=cache.k.dtype)
+            else:
+                new_cache = cache.append(k, v, positions[0])
+        else:
+            # single-token decode
+            if cp > 1:
+                new_cache = C.cp_append(cache, k, v, positions[0], cp)
+                out = C.cp_decode_attention(
+                    q, new_cache, lay.kv_map_global(), positions,
+                    window=window, softcap=cfg.attn_softcap)
+            else:
+                new_cache = cache.append(k, v, positions[0])
+                kq = C.expand_kv(new_cache.k, kv_map)
+                vq = C.expand_kv(new_cache.v, kv_map)
+                out = C.blockwise_attention(
+                    q, kq, vq, positions, new_cache.pos,
+                    causal=True, window=window, softcap=cfg.attn_softcap,
+                )
+        out = out.reshape(B, S, lay.hl * lay.head_dim)
+        return C.row_linear(out, p[prefix + "wo"], sp=sp), new_cache
 
 
 def mlp_block(p, x, cfg: ArchConfig, prefix="", sp: bool = False):
-    h = C.norm(cfg.norm, x, p[prefix + "norm2"])
-    h = C.sp_gather(h, sp) if sp else h
-    a = C.col_linear(h, p[prefix + "w1"])
-    if cfg.mlp in ("swiglu", "geglu"):
-        b = C.col_linear(h, p[prefix + "w3"])
-        act = jax.nn.silu(a) * b if cfg.mlp == "swiglu" else jax.nn.gelu(a) * b
-    else:
-        act = jax.nn.gelu(a)
-    return C.row_linear(act, p[prefix + "w2"], sp=sp)
+    with PROF.layer("mlp"):
+        h = C.norm(cfg.norm, x, p[prefix + "norm2"])
+        h = C.sp_gather(h, sp) if sp else h
+        a = C.col_linear(h, p[prefix + "w1"])
+        if cfg.mlp in ("swiglu", "geglu"):
+            b = C.col_linear(h, p[prefix + "w3"])
+            act = (jax.nn.silu(a) if cfg.mlp == "swiglu"
+                   else jax.nn.gelu(a)) * b
+        else:
+            act = jax.nn.gelu(a)
+        return C.row_linear(act, p[prefix + "w2"], sp=sp)
 
 
 def _res(cfg: ArchConfig, x, delta):
@@ -280,9 +284,10 @@ def moe_layer(p, x, cfg, lay, layer_idx, positions, cache, sp: bool = False,
     a, new_cache = attention_block(p, x, cfg, lay, layer_idx, positions, cache,
                                    sp=sp)
     x = _res(cfg, x, a)
-    h = C.norm(cfg.norm, x, p["norm2"])
-    h = C.sp_gather(h, sp) if sp else h
-    y, aux = MOE.moe_block(h, p, cfg, sp=sp, a2a_state=a2a_state)
+    with PROF.layer("mlp"):
+        h = C.norm(cfg.norm, x, p["norm2"])
+        h = C.sp_gather(h, sp) if sp else h
+        y, aux = MOE.moe_block(h, p, cfg, sp=sp, a2a_state=a2a_state)
     x = _res(cfg, x, y)
     return x, new_cache, aux
 
@@ -366,20 +371,22 @@ class DecoderLM:
 
     # ---- embedding / logits -------------------------------------------------
     def _embed(self, store, tokens, sp: bool = False):
-        emb = store.group("embed")["tok"]
-        x = C.vocab_parallel_embed(emb, tokens, sp=sp)
-        if self.cfg.emb_scale:
-            x = x * self.cfg.emb_scale
-        return x, emb
+        with PROF.layer("embed"):
+            emb = store.group("embed")["tok"]
+            x = C.vocab_parallel_embed(emb, tokens, sp=sp)
+            if self.cfg.emb_scale:
+                x = x * self.cfg.emb_scale
+            return x, emb
 
     def _logits(self, store, x, emb):
-        fin = store.group("final")
-        x = C.norm(self.cfg.norm, x, fin["norm_f"])
-        w = emb.T if self.cfg.tied_embeddings else fin["head"]
-        logits = C.vocab_parallel_logits(x, w)
-        if self.cfg.logit_scale:
-            logits = logits * self.cfg.logit_scale
-        return logits
+        with PROF.layer("head"):
+            fin = store.group("final")
+            x = C.norm(self.cfg.norm, x, fin["norm_f"])
+            w = emb.T if self.cfg.tied_embeddings else fin["head"]
+            logits = C.vocab_parallel_logits(x, w)
+            if self.cfg.logit_scale:
+                logits = logits * self.cfg.logit_scale
+            return logits
 
     # ---- full forward over a sequence (train / prefill) --------------------
     def forward(self, store, tokens, *, caches: DecodeState | None = None,
@@ -429,7 +436,8 @@ class DecoderLM:
             if remat:
                 body = jax.checkpoint(body, prevent_cse=False)
             sl_xs = (xs, idxs) if ef is None else (xs, idxs, ef)
-            (x, aux), new_ef_stack = jax.lax.scan(body, (x, aux0), sl_xs)
+            with PROF.layer("layers"):
+                (x, aux), new_ef_stack = jax.lax.scan(body, (x, aux0), sl_xs)
             if ef is not None:
                 aux = {**aux, "moe_a2a_state": new_ef_stack}
             new_caches = None
@@ -445,12 +453,14 @@ class DecoderLM:
 
             if remat:
                 body = jax.checkpoint(body, prevent_cse=False)
-            (x, aux), _ = jax.lax.scan(body, (x, aux0), xs)
+            with PROF.layer("layers"):
+                (x, aux), _ = jax.lax.scan(body, (x, aux0), xs)
             new_caches = None
 
         elif cfg.family == "hybrid":
-            x, aux, new_caches = self._hybrid_forward(store, x, positions, None,
-                                                      aux0, remat, sp=sp)
+            with PROF.layer("layers"):
+                x, aux, new_caches = self._hybrid_forward(
+                    store, x, positions, None, aux0, remat, sp=sp)
         else:
             raise ValueError(cfg.family)
 
@@ -587,9 +597,10 @@ class DecoderLM:
         logits, aux, _ = self.forward(store, inputs, remat=remat,
                                       moe_a2a_state=moe_a2a_state)
         new_ef = aux.pop("moe_a2a_state", None)
-        loss = C.vocab_parallel_xent(
-            logits, targets, self.cfg.vocab, softcap=self.cfg.final_softcap
-        )
+        with PROF.layer("head"):
+            loss = C.vocab_parallel_xent(
+                logits, targets, self.cfg.vocab, softcap=self.cfg.final_softcap
+            )
         total = loss
         if self.cfg.n_experts:
             total = total + self.cfg.aux_loss_coef * aux["aux"] + self.cfg.router_z_coef * aux["z"]

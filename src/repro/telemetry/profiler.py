@@ -1,14 +1,19 @@
-"""Phase-level trace annotation for the sync path (DESIGN.md §14).
+"""Trace-time scopes for the step's phases and layers (DESIGN.md §14).
 
 Two complementary mechanisms:
 
-* :func:`phase` — a ``jax.named_scope`` wrapper applied at *trace* time
-  around the sync phases (``encode`` -> ``exchange`` -> ``decode`` in
-  core/comm, ``apply``/``metrics`` in launch/steps).  The scope names land
-  in the lowered HLO metadata (``op_name=".../loco/encode/..."``), so XLA
-  profiler traces and HLO dumps show the comm structure by name.  Opcode
-  and instruction-name text are unchanged, so ``analysis.hlo_stats``
-  parses annotated modules identically (pinned in tests/test_metrics.py).
+* :func:`phase` and :func:`layer` — ``jax.named_scope`` wrappers applied
+  at *trace* time.  ``phase`` names the sync path (``loco/gather`` around
+  the forward weight all-gather in core/hijack, ``encode`` -> ``exchange``
+  -> ``decode`` in core/comm, ``apply``/``metrics`` in launch/steps);
+  ``layer`` names the model's parts (``model/embed``, ``model/layers``,
+  ``model/attention``, ``model/mlp``, ``model/head`` in
+  models/transformer).  The names land in the lowered HLO metadata
+  (``op_name=".../loco/encode/..."``), so XLA profiler traces and HLO
+  dumps show the step's structure by name; an op belongs to the innermost
+  scope on its path.  They are metadata only: opcode and instruction-name
+  text are unchanged, so ``analysis.hlo_stats`` parses annotated modules
+  identically (pinned in tests/test_metrics.py and tests/test_scopes.py).
 * :class:`TraceSession` + :func:`parse_window` — host-side capture of a
   ``jax.profiler.start_trace`` dir for a step window (``--profile-steps
   N:M`` in launch/train.py).  A failed start or stop raises: a run asked
@@ -18,7 +23,8 @@ from __future__ import annotations
 
 import jax
 
-PHASES = ("encode", "exchange", "decode", "apply", "metrics", "probe")
+PHASES = ("gather", "encode", "exchange", "decode", "apply", "metrics",
+          "probe")
 
 
 def phase(name: str, group: int | None = None):
@@ -33,6 +39,14 @@ def phase(name: str, group: int | None = None):
     if group is None:
         return jax.named_scope(f"loco/{name}")
     return jax.named_scope(f"loco/{name}/g{group}")
+
+
+def layer(name: str):
+    """Named scope for one part of the model (trace-time; nestable):
+    ``embed``, ``layers`` (the layer scan), ``attention``, ``mlp`` and
+    ``head`` make ``model/<name>``, ``model/attention`` nesting inside
+    ``model/layers``."""
+    return jax.named_scope(f"model/{name}")
 
 
 def parse_window(spec: str) -> tuple[int, int]:

@@ -117,184 +117,3 @@ def test_collective_launch_counts_trip_weighted(mesh22):
     txt = fn.lower(jnp.zeros((64,), jnp.float32)).compile().as_text()
     counts = collective_launches(txt)
     assert counts.get("all-reduce", 0) == 5, counts
-
-
-# ---------------------------------------------------------------------------
-# compute/collective overlap estimator (DESIGN.md §14)
-# ---------------------------------------------------------------------------
-
-_ASYNC_HLO = """\
-HloModule m
-
-%add (a: f32[], b: f32[]) -> f32[] {
-  %a = f32[] parameter(0)
-  %b = f32[] parameter(1)
-  ROOT %r = f32[] add(%a, %b)
-}
-
-ENTRY %main (p0: f32[1024], p1: f32[16]) -> f32[1024] {
-  %p0 = f32[1024] parameter(0)
-  %p1 = f32[16] parameter(1)
-  %ars = f32[1024] all-reduce-start(%p0), replica_groups={{0,1,2,3}}, to_apply=%add
-  %t = f32[16] add(%p1, %p1)
-  %ard = f32[1024] all-reduce-done(%ars)
-  ROOT %out = f32[1024] add(%ard, %ard)
-}
-"""
-
-
-def test_overlap_async_window_partial():
-    """Hand-countable async module at unit bandwidths: the all-reduce
-    moves 2 * 4096 * 3/4 = 6144 wire bytes; the only compute inside the
-    start..done window is a 64-byte elementwise add, so exactly 64 byte-
-    seconds are hideable."""
-    from repro.analysis.hlo_stats import overlap_stats
-
-    st = overlap_stats(_ASYNC_HLO, peak_flops=1.0, hbm_bw=1.0, ici_bw=1.0)
-    assert st.collective_s == 6144.0
-    assert st.n_async == 1 and st.n_sync == 0
-    assert st.hidden_s == 64.0  # the f32[16] add's result bytes
-    np.testing.assert_allclose(st.overlap_fraction, 64.0 / 6144.0)
-    assert st.exposed_s == 6144.0 - 64.0
-
-
-def test_overlap_async_fully_hidden():
-    """Enough compute inside the window caps hidden at the wire time."""
-    from repro.analysis.hlo_stats import overlap_stats
-
-    hlo = _ASYNC_HLO.replace("f32[16]", "f32[8192]")
-    st = overlap_stats(hlo, peak_flops=1.0, hbm_bw=1.0, ici_bw=1.0)
-    assert st.collective_s == 6144.0
-    assert st.hidden_s == 6144.0  # min(wire, 32768-byte add)
-    assert st.overlap_fraction == 1.0
-
-
-def test_overlap_sync_collective_exposes_everything():
-    """A synchronous collective (no -start/-done pair) hides nothing even
-    with compute adjacent to it."""
-    from repro.analysis.hlo_stats import overlap_stats
-
-    hlo = _ASYNC_HLO.replace(
-        "%ars = f32[1024] all-reduce-start(%p0)",
-        "%ars = f32[1024] all-reduce(%p0)").replace(
-        "%ard = f32[1024] all-reduce-done(%ars)",
-        "%ard = f32[1024] add(%ars, %ars)")
-    st = overlap_stats(hlo, peak_flops=1.0, hbm_bw=1.0, ici_bw=1.0)
-    assert st.collective_s == 6144.0
-    assert st.n_sync == 1 and st.n_async == 0
-    assert st.hidden_s == 0.0
-    assert st.overlap_fraction == 0.0
-
-
-_PIPELINED_HLO = """\
-HloModule pipe
-
-%add (a: f32[], b: f32[]) -> f32[] {
-  %a = f32[] parameter(0)
-  %b = f32[] parameter(1)
-  ROOT %r = f32[] add(%a, %b)
-}
-
-%cond (s: (s32[], f32[1024], f32[64])) -> pred[] {
-  %s = (s32[], f32[1024], f32[64]) parameter(0)
-  %i = s32[] get-tuple-element(%s), index=0
-  %n = s32[] constant(4)
-  ROOT %lt = pred[] compare(%i, %n), direction=LT
-}
-
-%body (s: (s32[], f32[1024], f32[64])) -> (s32[], f32[1024], f32[64]) {
-  %s = (s32[], f32[1024], f32[64]) parameter(0)
-  %i = s32[] get-tuple-element(%s), index=0
-  %g = f32[1024] get-tuple-element(%s), index=1
-  %x = f32[64] get-tuple-element(%s), index=2
-  %xc = f32[64] add(%x, %x)
-  %one = s32[] constant(1)
-  %ip = s32[] add(%i, %one)
-  %prev = f32[1024] all-reduce-done(%g)
-  %next = f32[1024] all-reduce-start(%prev), replica_groups={{0,1,2,3}}, to_apply=%add
-  ROOT %t = (s32[], f32[1024], f32[64]) tuple(%ip, %next, %xc)
-}
-
-ENTRY %main (p0: f32[1024], p1: f32[64]) -> f32[1024] {
-  %p0 = f32[1024] parameter(0)
-  %p1 = f32[64] parameter(1)
-  %zero = s32[] constant(0)
-  %init = (s32[], f32[1024], f32[64]) tuple(%zero, %p0, %p1)
-  %w = (s32[], f32[1024], f32[64]) while(%init), condition=%cond, body=%body
-  %gf = f32[1024] get-tuple-element(%w), index=1
-  %pc = f32[64] add(%p1, %p1)
-  ROOT %fin = f32[1024] all-reduce-done(%gf)
-}
-"""
-
-
-def test_overlap_pipelined_cross_computation_windows():
-    """Software-pipelined schedule (the overlap schedule of DESIGN.md §15,
-    and XLA collective pipelining): each iteration's -start closes with the
-    -done at the TOP of the next iteration, and the last start's done sits
-    after the loop.  No window opens and closes in one program-order walk,
-    so these starts were previously dropped from the hidden total.
-
-    Hand count at unit bandwidths: body compute before the done is the
-    f32[64] add (256) + s32[] add (4) = 260 byte-seconds; wire per
-    all-reduce is 2 * 4096 * 3/4 = 6144.  Three iteration crossings hide
-    min(6144, 0 + 260) each; the last start re-opens in ENTRY, accrues the
-    f32[64] add (256) there, and is closed FIFO by the epilogue done."""
-    from repro.analysis.hlo_stats import overlap_stats
-
-    st = overlap_stats(_PIPELINED_HLO, peak_flops=1.0, hbm_bw=1.0,
-                       ici_bw=1.0)
-    assert st.collective_s == 4 * 6144.0
-    assert st.n_async == 4 and st.n_sync == 0
-    assert st.hidden_s == 3 * 260.0 + 256.0
-    assert st.overlap_fraction > 0
-
-
-def test_overlap_pipelined_start_last_done_first_hides_nothing():
-    """The degenerate body order {done; compute; start} has the window in
-    flight only across the iteration boundary with no compute between the
-    start (last op) and the next done (first op): crossings hide zero, and
-    only the ENTRY epilogue compute is credited to the final window."""
-    from repro.analysis.hlo_stats import overlap_stats
-
-    hlo = _PIPELINED_HLO.replace("""  %xc = f32[64] add(%x, %x)
-  %one = s32[] constant(1)
-  %ip = s32[] add(%i, %one)
-  %prev = f32[1024] all-reduce-done(%g)
-  %next = f32[1024] all-reduce-start(%prev), replica_groups={{0,1,2,3}}, to_apply=%add
-""", """  %prev = f32[1024] all-reduce-done(%g)
-  %xc = f32[64] add(%x, %x)
-  %one = s32[] constant(1)
-  %ip = s32[] add(%i, %one)
-  %next = f32[1024] all-reduce-start(%prev), replica_groups={{0,1,2,3}}, to_apply=%add
-""")
-    st = overlap_stats(hlo, peak_flops=1.0, hbm_bw=1.0, ici_bw=1.0)
-    assert st.collective_s == 4 * 6144.0
-    assert st.hidden_s == 256.0  # epilogue window only
-
-
-def test_overlap_consistent_with_analyze(mesh22):
-    """On a real compiled module the estimator's totals must agree with
-    analyze(): same wire time (at ICI bandwidth), same launch count, and
-    a fraction inside [0, 1]."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.analysis.hlo_stats import analyze, overlap_stats
-    from repro.analysis.roofline import ICI_BW
-
-    def body(x):
-        def f(c, _):
-            return jax.lax.psum(c * 2.0, "data"), None
-        y, _ = jax.lax.scan(f, x, None, length=3)
-        return y
-
-    fn = jax.jit(jax.shard_map(body, mesh=mesh22, in_specs=P("data"),
-                               out_specs=P("data"), check_vma=False))
-    txt = fn.lower(jnp.zeros((1024,), jnp.float32)).compile().as_text()
-    st = overlap_stats(txt)
-    a = analyze(txt)
-    np.testing.assert_allclose(st.collective_s, a.wire_bytes / ICI_BW,
-                               rtol=1e-9)
-    assert st.n_async + st.n_sync == sum(a.coll_counts.values())
-    assert 0.0 <= st.overlap_fraction <= 1.0
-    assert st.compute_s > 0.0

@@ -107,6 +107,8 @@ def test_compile_cache_location(monkeypatch, tmp_path, from_env):
     from repro.launch import train as T
 
     before = jax.config.jax_compilation_cache_dir
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
+    canon = jax.config.jax_hlo_source_file_canonicalization_regex
     try:
         if from_env:
             monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
@@ -118,5 +120,16 @@ def test_compile_cache_location(monkeypatch, tmp_path, from_env):
             assert T.use_compile_cache() == want
             assert jax.config.jax_compilation_cache_dir == want
             assert T.use_compile_cache() == want  # idempotent
+        # the key holds the HLO metadata, with source files named from the
+        # checkout root: the same code elsewhere finds the same entries
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        root = str(Path(__file__).resolve().parents[1])
+        text = jax.jit(lambda x: x + 1).lower(1.0).as_text(debug_info=True)
+        assert "tests/test_train_integration.py" in text
+        assert root not in text
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          keyed)
+        jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                          canon)
